@@ -24,6 +24,9 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         ghost_exchange,
         io_ablation,

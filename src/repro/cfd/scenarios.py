@@ -36,7 +36,9 @@ def karman_vortex(nx: int = 64, ny: int = 256, re: float = 100.0) -> tuple[Fluid
         nx=nx,
         ny=ny,
         h=h,
-        dt=0.2 * h / u_in,
+        # explicit Euler: advective CFL 0.2, and ν·dt/h² ≤ 0.2 < 1/4 (2-D
+        # diffusion), which binds from nx ≈ 400 on
+        dt=min(0.2 * h / u_in, 0.2 * h * h / nu),
         nu=nu,
         u_in=u_in,
         mg=MGConfig(n_pre=2, n_post=2),

@@ -31,14 +31,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:  # newer jax: public alias + check_vma kwarg
-    shard_map = jax.shard_map
-    _SM_NOCHECK = {"check_vma": False}
-except AttributeError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map
-
-    _SM_NOCHECK = {"check_rep": False}
-
 
 def collective_plan(mesh: Mesh, axis: str, counts: np.ndarray) -> tuple[int, np.ndarray]:
     """Device-side reduce + exscan over per-shard grid counts.
@@ -52,11 +44,11 @@ def collective_plan(mesh: Mesh, axis: str, counts: np.ndarray) -> tuple[int, np.
         raise ValueError(f"counts must have shape ({n},), got {counts.shape}")
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(axis),
         out_specs=(P(), P(axis)),
-        **_SM_NOCHECK,
+        check_vma=False,
     )
     def plan(c):
         # c: (1,) — this shard's grid count
@@ -90,11 +82,11 @@ def gather_to_aggregators(
     group = n // n_aggregators
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(axis),
         out_specs=P(axis),
-        **_SM_NOCHECK,
+        check_vma=False,
     )
     def gather(block):
         # Gather the whole axis, then slice this shard's group window.  On a

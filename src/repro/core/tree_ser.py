@@ -4,7 +4,8 @@ Snapshots must be self-describing (paper §3: HDF5 self-description), so the
 tree *structure* is stored as a JSON skeleton in the step group's attributes
 and every leaf becomes one dataset addressed by a stable path string.
 Supported containers: dict / list / tuple / None; leaves: numpy/JAX arrays
-and python or numpy scalars (stored as 0-d arrays to keep dtype fidelity).
+and python or numpy scalars (stored as 0-d arrays to keep dtype fidelity,
+returned as Python scalars).
 """
 
 from __future__ import annotations
@@ -46,7 +47,10 @@ def flatten_state(tree: Any, prefix: str = "") -> tuple[Any, dict[str, np.ndarra
             raise TypeError(f"unsupported leaf at {path!r}: {type(node)}")
         key = path.lstrip(".") or "root"
         leaves[key] = arr
-        return {_LEAF: key, "scalar": np.ndim(node) == 0 and not isinstance(node, np.ndarray)}
+        # a 0-d *array* (numpy or jax, e.g. a train state's step counter)
+        # comes back as a 0-d array: as a Python scalar it would be weakly
+        # typed on the device and build another step program after resume
+        return {_LEAF: key, "scalar": isinstance(node, (bool, int, float, complex, np.generic))}
 
     skeleton = rec(tree, prefix)
     return skeleton, leaves

@@ -19,7 +19,7 @@ def mha(
     v: jax.Array,
     *,
     window: int = 0,
-    interpret: bool = True,
+    interpret: bool = False,
     use_ref: bool = False,
 ) -> jax.Array:
     B, S, H, Dh = q.shape

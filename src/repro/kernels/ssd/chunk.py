@@ -81,7 +81,7 @@ def ssd_chunk(
     s_in: jax.Array,  # (B, H, P, N)
     *,
     hb: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """One chunk step: returns (y (B,Q,H,P), s_out (B,H,P,N))."""
     B, Q, H, P = x.shape

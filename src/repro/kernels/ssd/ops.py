@@ -14,7 +14,7 @@ from .ref import ssd_chunk_ref
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret", "use_ref"))
-def ssd_scan(x, dt, A, b, c, *, chunk: int = 256, interpret: bool = True, use_ref: bool = False):
+def ssd_scan(x, dt, A, b, c, *, chunk: int = 256, interpret: bool = False, use_ref: bool = False):
     """x (B,S,H,P); dt (B,S,H); A (H,)<0; b/c (B,S,N) → y (B,S,H,P), state."""
     B, S, H, P = x.shape
     N = b.shape[-1]

@@ -43,7 +43,7 @@ def jacobi_sweep(
     h2: float,
     omega: float = 1.0,
     *,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """One weighted-Jacobi sweep over a batch of d-grids → (G, n, n)."""
     G, np2, _ = p.shape
@@ -71,7 +71,7 @@ def _residual_kernel(p_ref, f_ref, o_ref, *, inv_h2: float):
 
 
 @functools.partial(jax.jit, static_argnames=("h2", "interpret"))
-def residual(p: jax.Array, f: jax.Array, h2: float, *, interpret: bool = True) -> jax.Array:
+def residual(p: jax.Array, f: jax.Array, h2: float, *, interpret: bool = False) -> jax.Array:
     """r = f − ∇²p on each d-grid → (G, n, n)."""
     G, np2, _ = p.shape
     n = np2 - 2

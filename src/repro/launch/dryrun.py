@@ -1,5 +1,8 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"
+).strip()
 
 """Multi-pod dry-run (deliverable e) + roofline extraction (deliverable g).
 
@@ -9,10 +12,13 @@ compiles the real step function — ``train_step`` / ``prefill_step`` /
 records ``memory_analysis()``, ``cost_analysis()`` and the HLO collective
 traffic into ``results/dryrun/<cell>.json``.
 
-The two XLA_FLAGS lines above MUST stay the first statements in this
-module: jax locks the device count on first backend initialisation, and
-the production meshes need 512 host devices.  Nothing else in the repo
-sets this flag — smoke tests and benchmarks see one device.
+The environment lines above MUST stay the first statements in this
+module: jax locks the platform and the device count on first backend
+initialisation.  The dry-run is a host-device compile tool, so it pins the
+CPU platform (on a TPU host it would otherwise take the chip and fail for
+want of 512 devices) and appends the 512-device flag to any ``XLA_FLAGS``
+already set.  Nothing else in the repo sets this flag — smoke tests and
+benchmarks see one device.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-8b \

@@ -15,12 +15,7 @@ kept to gradient reductions (see ``distributed.sharding``).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType only exists on newer jax; older jax is Auto-only anyway
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 # v5e hardware constants used by the roofline analysis (per chip).
 PEAK_BF16_FLOPS = 197e12  # FLOP/s
@@ -33,8 +28,6 @@ DCN_BW_PER_HOST = 25e9 / 8  # ~25 Gb/s NIC per host, bytes/s (cross-pod axis)
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None) -> Mesh:
     """`jax.make_mesh` with explicit Auto axis types (pjit-style sharding)."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes, devices=devices)
     return jax.make_mesh(
         shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
     )

@@ -19,15 +19,17 @@ Features (the large-scale-runnability checklist):
 
 from __future__ import annotations
 
+import dataclasses
 import time
-from dataclasses import dataclass, field
-from typing import Any, Callable
+from dataclasses import dataclass
+from functools import partial
+from typing import Callable
 
 import jax
-import numpy as np
 
 from ..core.checkpoint import AsyncCheckpointer, CheckpointManager
 from ..core.steering import BranchManager
+from ..distributed import sharding
 from ..models.common import ModelConfig
 from .data import DataConfig, TokenStream
 from .steps import TrainSetup, init_train_state, make_train_step
@@ -67,8 +69,27 @@ class Trainer:
         self.manager = manager
         self.async_ckpt = AsyncCheckpointer(manager)
         self.stream = TokenStream(cfg, data or DataConfig())
-        step_fn, _, _ = make_train_step(cfg, mesh=mesh, setup=self.setup)
-        self.step_fn = jax.jit(step_fn, donate_argnums=0)
+        self.mesh = mesh
+        step_fn, state_specs, batch_specs = make_train_step(cfg, mesh=mesh, setup=self.setup)
+        init = partial(init_train_state, cfg=cfg, setup=self.setup)
+        if mesh is None:
+            self.state_sharding = self.batch_sharding = None
+            self.step_fn = jax.jit(step_fn, donate_argnums=0)
+            self._init = jax.jit(init)
+        else:
+            # the state lives where the specs put it, fresh or resumed, and
+            # the step keeps it there (outputs pinned to the input layout)
+            state_sds = jax.eval_shape(init, jax.random.PRNGKey(0))
+            state_specs = sharding.fix_specs(mesh, state_specs, state_sds)
+            self.state_sharding = sharding.to_named(mesh, state_specs)
+            self.batch_sharding = sharding.to_named(mesh, batch_specs)
+            self.step_fn = jax.jit(
+                step_fn,
+                in_shardings=(self.state_sharding, self.batch_sharding),
+                out_shardings=(self.state_sharding, None),
+                donate_argnums=0,
+            )
+            self._init = jax.jit(init, out_shardings=self.state_sharding)
         self.state: dict | None = None
         self.metrics: list[dict] = []
         self.straggler = StragglerStats()
@@ -80,11 +101,15 @@ class Trainer:
         latest = self.manager.latest_valid()
         if latest is not None:
             _, snap = self.manager.restore(latest)
-            self.state = snap["train_state"]
-            start = int(snap["train_state"]["step"])
-            return start
-        self.state = init_train_state(jax.random.PRNGKey(seed), self.cfg, self.setup)
+            self.state = self.place(snap["train_state"])
+            return int(self.state["step"])
+        self.state = self._init(jax.random.PRNGKey(seed))
         return 0
+
+    def place(self, state: dict) -> dict:
+        """Host (or device) state → device arrays with this trainer's layout
+        (the default device without a mesh)."""
+        return jax.device_put(state, self.state_sharding)
 
     def _checkpoint(self, step: int) -> None:
         payload = {
@@ -104,7 +129,7 @@ class Trainer:
         end = start + (n_steps if n_steps is not None else self.tcfg.total_steps)
         for step in range(start, end):
             t0 = time.perf_counter()
-            batch = self.stream.batch(step)
+            batch = jax.device_put(self.stream.batch(step), self.batch_sharding)
             self.state, metrics = self.step_fn(self.state, batch)
             loss = float(metrics["loss"])  # blocks → true step time
             dt = time.perf_counter() - t0
@@ -137,8 +162,6 @@ class Trainer:
         bm = BranchManager(self.manager)
         child_bm = bm.branch(at_step, child_path, overlay=overlay)
         _, snap = child_bm.restore(at_step)
-        import dataclasses
-
         new_setup = dataclasses.replace(self.setup, **setup_edits) if setup_edits else self.setup
         t = Trainer(
             self.cfg,
@@ -146,6 +169,7 @@ class Trainer:
             setup=new_setup,
             data=self.stream.dcfg,
             tcfg=self.tcfg,
+            mesh=self.mesh,
         )
-        t.state = snap["train_state"]
+        t.state = t.place(snap["train_state"])
         return t

@@ -219,3 +219,19 @@ def test_codec_policy_default_table(tmp_path):
         # an explicit per-call policy still overrides the manager's
         res2 = mgr.save(1, state, codec_policy=CodecPolicy(default="none"))
         assert res2.filter_stats.n_chunks == 0
+
+
+def test_zero_d_device_array_restores_as_array(tmp_path):
+    """A 0-d jax array (a train state's step counter) saved synchronously
+    comes back as a 0-d int32 array, not a Python int; Python and numpy
+    scalars still come back as Python scalars."""
+    import jax.numpy as jnp
+
+    state = {"step": jnp.asarray(7, jnp.int32), "t": np.float64(0.5), "n": 3}
+    with CheckpointManager(str(tmp_path / "s.th5")) as mgr:
+        mgr.save(0, state)
+        _, got = mgr.restore(0)
+    assert isinstance(got["step"], np.ndarray) and got["step"].dtype == np.int32
+    assert got["step"].shape == () and int(got["step"]) == 7
+    assert got["t"] == 0.5 and isinstance(got["t"], float)
+    assert got["n"] == 3 and isinstance(got["n"], int)

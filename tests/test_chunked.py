@@ -3,6 +3,8 @@ filter pipeline, variable-length file domains, LRU chunk cache, and the
 checkpoint codec policy (docs/FORMAT.md is the layout spec)."""
 
 import os
+import tempfile
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -50,7 +52,7 @@ def _roundtrip(tmp_path, data, chunk_rows, codec, name="rt.th5"):
     dtype=st.sampled_from(["<f4", "<f8", "<i4", "<u1"]),
     seed=st.integers(min_value=0, max_value=2**16),
 )
-def test_lossless_roundtrip_bitexact(tmp_path, rows, cols, chunk_rows, codec, dtype, seed):
+def test_lossless_roundtrip_bitexact(rows, cols, chunk_rows, codec, dtype, seed):
     """Any (shape, chunk size, lossless codec) combination round-trips
     bit-exact, including chunk_rows > rows and ragged final chunks."""
     rng = np.random.default_rng(seed)
@@ -59,7 +61,8 @@ def test_lossless_roundtrip_bitexact(tmp_path, rows, cols, chunk_rows, codec, dt
         data = (rng.integers(0, 32, (rows, cols)) / 32).astype(dt)
     else:
         data = rng.integers(0, 100, (rows, cols)).astype(dt)
-    got, meta = _roundtrip(tmp_path, data, chunk_rows, codec)
+    with tempfile.TemporaryDirectory() as d:  # one directory per example
+        got, meta = _roundtrip(Path(d), data, chunk_rows, codec)
     np.testing.assert_array_equal(got, data)
     assert len(meta.chunks) == -(-rows // min(chunk_rows, 80))
 
@@ -71,10 +74,11 @@ def test_lossless_roundtrip_bitexact(tmp_path, rows, cols, chunk_rows, codec, dt
     chunk_rows=st.integers(min_value=1, max_value=64),
     seed=st.integers(min_value=0, max_value=2**16),
 )
-def test_lossy_roundtrip_within_stored_scale_tolerance(tmp_path, rows, cols, chunk_rows, seed):
+def test_lossy_roundtrip_within_stored_scale_tolerance(rows, cols, chunk_rows, seed):
     rng = np.random.default_rng(seed)
     data = ((rng.random((rows, cols)) - 0.5) * 10).astype(np.float32)
-    got, _ = _roundtrip(tmp_path, data, chunk_rows, "int8-blockq")
+    with tempfile.TemporaryDirectory() as d:
+        got, _ = _roundtrip(Path(d), data, chunk_rows, "int8-blockq")
     assert np.abs(got.astype(np.float64) - data).max() <= Int8BlockQCodec.tolerance(data)
 
 
@@ -107,7 +111,7 @@ def test_byte_shuffle_is_a_pure_permutation(n_elems, dtype, seed):
     dtype=st.sampled_from(["<f4", "<f8"]),
     seed=st.integers(min_value=0, max_value=2**16),
 )
-def test_shuffle_zlib_roundtrip_bitexact(tmp_path, rows, cols, chunk_rows, dtype, seed):
+def test_shuffle_zlib_roundtrip_bitexact(rows, cols, chunk_rows, dtype, seed):
     """The shuffle pre-filter stays bit-exact across shape × dtype × chunk
     size, including ragged final chunks and chunk_rows > rows — and the
     written chunks survive the byte-balanced file-domain split (the
@@ -115,7 +119,8 @@ def test_shuffle_zlib_roundtrip_bitexact(tmp_path, rows, cols, chunk_rows, dtype
     rng = np.random.default_rng(seed)
     dt = np.dtype(dtype)
     data = ((rng.integers(0, 256, (rows, cols)) / 256) * 8 - 4).astype(dt)
-    got, meta = _roundtrip(tmp_path, data, chunk_rows, "shuffle+zlib")
+    with tempfile.TemporaryDirectory() as d:
+        got, meta = _roundtrip(Path(d), data, chunk_rows, "shuffle+zlib")
     np.testing.assert_array_equal(got, data)
     assert len(meta.chunks) == -(-rows // min(chunk_rows, 80))
 

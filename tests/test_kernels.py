@@ -143,7 +143,7 @@ def test_jacobi_converges_on_poisson():
 # -- pack ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("G,n", [(4, 16), (2, 8), (1, 32)])
+@pytest.mark.parametrize("G,n", [(4, 16), (2, 8), (1, 32), (12, 16)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
 def test_pack_grids_matches_ref(G, n, dtype):
     if dtype == jnp.int32:

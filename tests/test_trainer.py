@@ -166,3 +166,58 @@ def test_straggler_watchdog(tmp_path):
     assert t.straggler.flagged == 1
     assert t.straggler.slowest_s == pytest.approx(0.1)
     t.manager.close()
+
+
+PLACEMENT_CODE = r"""
+import tempfile
+import jax
+import numpy as np
+from repro.configs import get_smoke
+from repro.core.checkpoint import CheckpointManager
+from repro.launch.mesh import make_test_mesh
+from repro.train.data import DataConfig
+from repro.train.trainer import Trainer, TrainerConfig
+
+cfg = get_smoke("qwen3-8b").scaled(logit_chunk=64)
+mesh = make_test_mesh(2, 2)
+
+def trainer(path):
+    return Trainer(cfg, CheckpointManager(path), mesh=mesh,
+                   data=DataConfig(batch=4, seq_len=32, seed=7),
+                   tcfg=TrainerConfig(checkpoint_every=2))
+
+def check_placed(t):
+    want = jax.tree.leaves(t.state_sharding)
+    got = jax.tree.leaves(t.state)
+    assert len(got) == len(want)
+    for leaf, sh in zip(got, want):
+        assert leaf.sharding == sh, (leaf.sharding, sh)
+    # the embedding is split over the whole 2x2 mesh
+    emb = t.state["params"]["embed"]
+    assert len({s.device for s in emb.addressable_shards}) == 4
+
+with tempfile.TemporaryDirectory() as d:
+    path = d + "/run.th5"
+    t1 = trainer(path)
+    assert t1.init_or_resume(seed=3) == 0
+    check_placed(t1)
+    t1.run(2)
+    check_placed(t1)  # the step keeps the layout
+    saved = [np.asarray(x) for x in jax.tree.leaves(t1.state)]
+    t1.manager.close()
+    t2 = trainer(path)
+    assert t2.init_or_resume() == 2
+    check_placed(t2)
+    for a, b in zip(saved, jax.tree.leaves(t2.state)):
+        np.testing.assert_array_equal(a, np.asarray(b))
+    t2.manager.close()
+print("OK")
+"""
+
+
+def test_trainer_places_state_with_mesh_specs():
+    """On a 2x2 mesh every leaf of a fresh or resumed state carries the
+    sharding the train step's specs give it."""
+    from tests._subproc import run_with_devices
+
+    assert "OK" in run_with_devices(PLACEMENT_CODE, 4)
