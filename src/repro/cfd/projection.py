@@ -15,7 +15,6 @@ boundaries: cell_type masks force u=v=0 (and Dirichlet T) inside solids.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -127,5 +126,10 @@ from functools import lru_cache
 @lru_cache(maxsize=32)
 def make_step(cfg: FluidConfig):
     """jit-compiled step, cached per config — TRS branches with an unchanged
-    FluidConfig reuse the compiled executable (reload stays metadata-cheap)."""
-    return jax.jit(partial(step, cfg))
+    FluidConfig reuse the compiled executable (reload stays metadata-cheap).
+    A named closure, so traces call the executable ``jit_fluid_step``."""
+
+    def fluid_step(state: dict) -> dict:
+        return step(cfg, state)
+
+    return jax.jit(fluid_step)

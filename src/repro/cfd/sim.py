@@ -19,6 +19,14 @@ import numpy as np
 
 from ..core.checkpoint import CheckpointManager
 from ..core.steering import BranchManager
+from ..obs.trace import (
+    SPAN_SIM_FETCH,
+    SPAN_SIM_LAYOUT,
+    SPAN_SIM_LOAD,
+    SPAN_SIM_SNAPSHOT,
+    SPAN_SIM_TOPOLOGY,
+    TRACER,
+)
 from .projection import FluidConfig, make_step
 from .spacetree import TreeLayout, to_blocked, topology_arrays
 
@@ -62,32 +70,40 @@ class Simulation:
     def _pack_cells(self) -> np.ndarray:
         """Blocked (G, n², n_fields) cell rows — the linear write buffer."""
         blocks = []
-        for f in FIELDS:
-            b = to_blocked(self.layout, self.state[f])[:, 1:-1, 1:-1]
-            blocks.append(np.asarray(b).reshape(self.layout.G, -1))
+        with TRACER.phase(SPAN_SIM_FETCH) as fetch:
+            for f in FIELDS:
+                b = to_blocked(self.layout, self.state[f])[:, 1:-1, 1:-1]
+                blocks.append(np.asarray(b).reshape(self.layout.G, -1))
+            fetch.tag("bytes", sum(b.nbytes for b in blocks))
         return np.stack(blocks, axis=-1)  # (G, n², F)
 
     def snapshot(self) -> int:
-        step = self.step_index
-        cells = self._pack_cells()
-        prev = self._prev_cells if self._prev_cells is not None else cells
-        ct = np.asarray(
-            to_blocked(self.layout, self.state["cell_type"].astype(jnp.float32))[:, 1:-1, 1:-1]
-        ).astype(np.int8).reshape(self.layout.G, -1)
-        uids, subgrid, boxes, rank_of = topology_arrays(self.layout, self.n_ranks)
-        self.manager.save(
-            step,
-            {
-                "current_cell_data": cells,
-                "previous_cell_data": prev,
-                "cell_type": ct,
-                "t": np.float64(self.state["t"]),
-            },
-            n_ranks=self.n_ranks,
-            topology_override=(uids, subgrid, boxes),
-            extra_attrs={"sim_time": float(self.state["t"]), "fields": list(FIELDS)},
-        )
-        self._prev_cells = cells
+        with TRACER.phase(SPAN_SIM_SNAPSHOT) as snap:
+            with TRACER.phase(SPAN_SIM_FETCH, bytes=self.state["t"].nbytes):
+                step = self.step_index  # waits for the steps queued before it
+            snap.tag("step", step)
+            cells = self._pack_cells()
+            prev = self._prev_cells if self._prev_cells is not None else cells
+            with TRACER.phase(SPAN_SIM_FETCH) as fetch:
+                ct = np.asarray(
+                    to_blocked(self.layout, self.state["cell_type"].astype(jnp.float32))[:, 1:-1, 1:-1]
+                ).astype(np.int8).reshape(self.layout.G, -1)
+                fetch.tag("bytes", ct.nbytes)
+            with TRACER.phase(SPAN_SIM_TOPOLOGY, grids=self.layout.G):
+                uids, subgrid, boxes, rank_of = topology_arrays(self.layout, self.n_ranks)
+            self.manager.save(
+                step,
+                {
+                    "current_cell_data": cells,
+                    "previous_cell_data": prev,
+                    "cell_type": ct,
+                    "t": np.float64(self.state["t"]),
+                },
+                n_ranks=self.n_ranks,
+                topology_override=(uids, subgrid, boxes),
+                extra_attrs={"sim_time": float(self.state["t"]), "fields": list(FIELDS)},
+            )
+            self._prev_cells = cells
         return step
 
     # -- restart / TRS -----------------------------------------------------------------
@@ -98,18 +114,21 @@ class Simulation:
         return step
 
     def _load(self, snap: dict) -> None:
-        cells = snap["current_cell_data"]  # (G, n², F)
-        lay = self.layout
-        for fi, f in enumerate(FIELDS):
-            comp = (
-                cells[:, :, fi]
-                .reshape(lay.gx, lay.gy, lay.n, lay.n)
-                .transpose(0, 2, 1, 3)
-                .reshape(lay.gx * lay.n, lay.gy * lay.n)
-            )
-            self.state[f] = jnp.asarray(comp, jnp.float32)
-        self.state["t"] = jnp.asarray(np.float32(snap["t"]))
-        self._prev_cells = np.asarray(snap["previous_cell_data"])
+        with TRACER.phase(SPAN_SIM_LOAD):
+            cells = snap["current_cell_data"]  # (G, n², F)
+            lay = self.layout
+            for fi, f in enumerate(FIELDS):
+                with TRACER.phase(SPAN_SIM_LAYOUT) as layout:
+                    comp = (
+                        cells[:, :, fi]
+                        .reshape(lay.gx, lay.gy, lay.n, lay.n)
+                        .transpose(0, 2, 1, 3)
+                        .reshape(lay.gx * lay.n, lay.gy * lay.n)
+                    )
+                    layout.tag("bytes", comp.nbytes)
+                self.state[f] = jnp.asarray(comp, jnp.float32)
+            self.state["t"] = jnp.asarray(np.float32(snap["t"]))
+            self._prev_cells = np.asarray(snap["previous_cell_data"])
 
     def branch(self, at_step: int, child_path: str, overlay: dict | None = None, **state_edits: Any) -> "Simulation":
         """TRS: reload ``at_step``, apply steering edits, continue in a new

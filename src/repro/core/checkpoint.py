@@ -29,6 +29,15 @@ from typing import Any, Callable, Mapping, Sequence
 
 import numpy as np
 
+from repro.obs.trace import (
+    SPAN_CKPT_PLAN,
+    SPAN_CKPT_RESTORE,
+    SPAN_CKPT_SAVE,
+    SPAN_CKPT_SEAL,
+    SPAN_CKPT_WRITE,
+    TRACER,
+)
+
 from . import tree_ser, uid
 from .aggregation import (
     AggregationConfig,
@@ -308,107 +317,116 @@ class CheckpointManager:
         """
         if codec_policy is None:
             codec_policy = self.codec_policy
-        t0 = time.perf_counter()
-        skeleton, leaves = tree_ser.flatten_state(state)
-        group = _step_group(step)
-        with self._io_lock:
-            if self.file.exists(group):
-                if not overwrite:
-                    raise ValueError(f"step {step} already written")
-                # TRS replay over the same file: shadow paging makes dropping
-                # the old step group from the index safe (old extents become
-                # dead space; prior generations still reference them)
-                self.file.drop_subtree(group)
-            self.file.create_group(
-                group,
-                attrs={
-                    "step": int(step),
-                    "skeleton": skeleton,
-                    "n_ranks": int(n_ranks),
-                    "wall_time": time.time(),
-                    **dict(extra_attrs or {}),
-                },
-            )
-            # ---- collective creation: one planner allocates all extents ----
-            metas: dict[str, Any] = {}
-            plans: dict[str, Any] = {}
-            chunked: dict[str, str] = {}  # leaf path -> resolved codec
-            total_bytes = 0
-            for path, arr in leaves.items():
-                arr = np.asarray(arr, order="C")  # NB: ascontiguousarray would 0-d → (1,)
-                leaves[path] = arr
-                name = f"{group}/state/{path}"
-                codec = codec_policy.resolve(path, arr) if codec_policy else "none"
-                n_rows = arr.shape[0] if arr.ndim else 1
-                row_bytes = arr.nbytes // max(n_rows, 1)
-                if codec != "none":
-                    meta = self.file.create_chunked_dataset(
-                        name,
-                        arr.shape,
-                        arr.dtype,
-                        chunk_rows=codec_policy.chunk_rows_for(n_rows, row_bytes),
-                        codec=codec,
+        with TRACER.phase(SPAN_CKPT_SAVE, step=int(step)) as save:
+            t0 = time.perf_counter()
+            skeleton, leaves = tree_ser.flatten_state(state)
+            group = _step_group(step)
+            with self._io_lock:
+                if self.file.exists(group):
+                    if not overwrite:
+                        raise ValueError(f"step {step} already written")
+                    # TRS replay over the same file: shadow paging makes dropping
+                    # the old step group from the index safe (old extents become
+                    # dead space; prior generations still reference them)
+                    self.file.drop_subtree(group)
+                self.file.create_group(
+                    group,
+                    attrs={
+                        "step": int(step),
+                        "skeleton": skeleton,
+                        "n_ranks": int(n_ranks),
+                        "wall_time": time.time(),
+                        **dict(extra_attrs or {}),
+                    },
+                )
+                # ---- collective creation: one planner allocates all extents ----
+                metas: dict[str, Any] = {}
+                plans: dict[str, Any] = {}
+                chunked: dict[str, str] = {}  # leaf path -> resolved codec
+                total_bytes = 0
+                # C order for the pwrites: a leaf staged in another order is copied here
+                with TRACER.phase(SPAN_CKPT_PLAN) as planning:
+                    for path, arr in leaves.items():
+                        arr = np.asarray(arr, order="C")  # NB: ascontiguousarray would 0-d → (1,)
+                        leaves[path] = arr
+                        name = f"{group}/state/{path}"
+                        codec = codec_policy.resolve(path, arr) if codec_policy else "none"
+                        n_rows = arr.shape[0] if arr.ndim else 1
+                        row_bytes = arr.nbytes // max(n_rows, 1)
+                        if codec != "none":
+                            meta = self.file.create_chunked_dataset(
+                                name,
+                                arr.shape,
+                                arr.dtype,
+                                chunk_rows=codec_policy.chunk_rows_for(n_rows, row_bytes),
+                                codec=codec,
+                            )
+                            chunked[path] = codec
+                        else:
+                            meta = self.file.create_dataset(name, arr.shape, arr.dtype)
+                        plan = self._plan_for(n_rows, meta.row_bytes, n_ranks)
+                        metas[path], plans[path] = meta, plan
+                        total_bytes += arr.nbytes
+                    planning.tag("bytes", total_bytes)
+
+                # ---- independent writes into disjoint extents ----
+                reqs: list[list[WriteRequest]] = [[] for _ in range(n_ranks)]
+                for path, arr in leaves.items():
+                    if path in chunked:
+                        continue  # filtered leaves go through the chunk pipeline
+                    meta, plan = metas[path], plans[path]
+                    flat = arr.reshape((plan.total_rows if arr.ndim else 1, -1))
+                    for r in range(n_ranks):
+                        lo, hi = plan.row_range(r)
+                        if hi > lo:
+                            reqs[r].append(
+                                WriteRequest(meta.offset + plan.extents[r].offset, flat[lo:hi])
+                            )
+                writer = self._writer_for(aggregation)
+                with TRACER.phase(SPAN_CKPT_WRITE, bytes=total_bytes):
+                    stats = (
+                        writer.write_independent(reqs) if independent else writer.write_collective(reqs)
                     )
-                    chunked[path] = codec
+
+                    # ---- chunked leaves: encode in the aggregators, overlapped ----
+                    fstats = FilterStats()
+                    if chunked:
+                        pipe = self._pipeline_for(aggregation)
+                        for path in chunked:
+                            fstats.merge(pipe.write(metas[path], leaves[path]))
+
+                # ---- topology datasets (paper Fig. 4) ----
+                if topology_override is not None:
+                    uids, subgrid, boxes = topology_override
+                    for nm, arr, dt in (
+                        ("grid_property", np.asarray(uids, np.uint64), "<u8"),
+                        ("subgrid_uid", np.asarray(subgrid, np.uint64), "<u8"),
+                        ("bounding_box", np.asarray(boxes, np.float64), "<f8"),
+                    ):
+                        meta = self.file.create_dataset(f"{group}/topology/{nm}", arr.shape, dt)
+                        self.file.write_full(meta, arr, checksum=True)
                 else:
-                    meta = self.file.create_dataset(name, arr.shape, arr.dtype)
-                plan = self._plan_for(n_rows, meta.row_bytes, n_ranks)
-                metas[path], plans[path] = meta, plan
-                total_bytes += arr.nbytes
+                    self._write_topology(group, metas, plans, n_ranks)
 
-            # ---- independent writes into disjoint extents ----
-            reqs: list[list[WriteRequest]] = [[] for _ in range(n_ranks)]
-            for path, arr in leaves.items():
-                if path in chunked:
-                    continue  # filtered leaves go through the chunk pipeline
-                meta, plan = metas[path], plans[path]
-                flat = arr.reshape((plan.total_rows if arr.ndim else 1, -1))
-                for r in range(n_ranks):
-                    lo, hi = plan.row_range(r)
-                    if hi > lo:
-                        reqs[r].append(
-                            WriteRequest(meta.offset + plan.extents[r].offset, flat[lo:hi])
-                        )
-            writer = self._writer_for(aggregation)
-            stats = (
-                writer.write_independent(reqs) if independent else writer.write_collective(reqs)
-            )
+                for name, arr in dict(extra_datasets or {}).items():
+                    arr = np.ascontiguousarray(arr)
+                    meta = self.file.create_dataset(f"{group}/{name}", arr.shape, arr.dtype)
+                    self.file.write_full(meta, arr, checksum=checksum)
 
-            # ---- chunked leaves: encode in the aggregators, overlapped ----
-            fstats = FilterStats()
-            if chunked:
-                pipe = self._pipeline_for(aggregation)
-                for path in chunked:
-                    fstats.merge(pipe.write(metas[path], leaves[path]))
-
-            # ---- topology datasets (paper Fig. 4) ----
-            if topology_override is not None:
-                uids, subgrid, boxes = topology_override
-                for nm, arr, dt in (
-                    ("grid_property", np.asarray(uids, np.uint64), "<u8"),
-                    ("subgrid_uid", np.asarray(subgrid, np.uint64), "<u8"),
-                    ("bounding_box", np.asarray(boxes, np.float64), "<f8"),
-                ):
-                    meta = self.file.create_dataset(f"{group}/topology/{nm}", arr.shape, dt)
-                    self.file.write_full(meta, arr, checksum=True)
-            else:
-                self._write_topology(group, metas, plans, n_ranks)
-
-            for name, arr in dict(extra_datasets or {}).items():
-                arr = np.ascontiguousarray(arr)
-                meta = self.file.create_dataset(f"{group}/{name}", arr.shape, arr.dtype)
-                self.file.write_full(meta, arr, checksum=checksum)
-
-            if checksum:
-                for path in leaves:
-                    if path not in chunked:  # chunked leaves carry per-chunk CRCs
-                        self.file.seal_checksum(f"{group}/state/{path}")
-            gen = self.file.commit()  # shadow flip: snapshot becomes durable
+                if checksum:
+                    # chunked leaves carry per-chunk CRCs
+                    sealed = [path for path in leaves if path not in chunked]
+                    with TRACER.phase(SPAN_CKPT_SEAL, bytes=sum(leaves[path].nbytes for path in sealed)):
+                        for path in sealed:
+                            self.file.seal_checksum(f"{group}/state/{path}")
+                gen = self.file.commit()  # shadow flip: snapshot becomes durable
+            save.tag("bytes", total_bytes)
+            wall_s = time.perf_counter() - t0
         return SaveResult(
             step=step,
             generation=gen,
             bytes_data=total_bytes,
-            wall_s=time.perf_counter() - t0,
+            wall_s=wall_s,
             write_stats=stats,
             n_leaves=len(leaves),
             filter_stats=fstats,
@@ -443,18 +461,20 @@ class CheckpointManager:
 
     def restore(self, step: int | None = None, verify: bool = True) -> tuple[int, Any]:
         """Load a full snapshot → (step, state).  ``step=None`` = newest valid."""
-        if step is None:
-            step = self.latest_valid(verify=verify)
+        with TRACER.phase(SPAN_CKPT_RESTORE) as restore:
             if step is None:
-                raise FileNotFoundError(f"no valid snapshot in {self.path}")
-        group = _step_group(step)
-        attrs = self.file.group_attrs(group)
-        skeleton = attrs["skeleton"]
-        leaves = {
-            p: self.file.read(f"{group}/state/{p}", verify=verify)
-            for p in tree_ser.leaf_paths(skeleton)
-        }
-        return step, tree_ser.unflatten_state(skeleton, leaves)
+                step = self.latest_valid(verify=verify)
+                if step is None:
+                    raise FileNotFoundError(f"no valid snapshot in {self.path}")
+            restore.tag("step", int(step))
+            group = _step_group(step)
+            attrs = self.file.group_attrs(group)
+            skeleton = attrs["skeleton"]
+            leaves = {
+                p: self.file.read(f"{group}/state/{p}", verify=verify)
+                for p in tree_ser.leaf_paths(skeleton)
+            }
+            return step, tree_ser.unflatten_state(skeleton, leaves)
 
     def restore_leaf_shard(
         self, step: int, leaf_path: str, rank: int, n_ranks: int, verify: bool = False

@@ -63,6 +63,7 @@ from dataclasses import dataclass, field
 from typing import Any, Iterable, Mapping, Sequence
 
 from repro.obs import metrics as _metrics
+from repro.obs.trace import SPAN_CKPT_COMMIT, SPAN_CKPT_FSYNC, SPAN_TH5_READ, SPAN_TH5_VERIFY, TRACER
 
 import numpy as np
 
@@ -1384,35 +1385,38 @@ class TH5File:
 
     def read(self, name: str, verify: bool = False) -> np.ndarray:
         meta = self.meta(name)
-        dt = meta.np_dtype
-        if meta.is_chunked:
-            out = np.empty(meta.shape, dtype=dt.newbyteorder("="))
-            self._gather_rows_chunked(name, meta, 0, meta.n_rows, out, verify=verify)
-            return out
-        if self._is_native(dt):
-            # vectored read straight into the result array — no intermediate
-            # bytes object between the page cache and the caller's buffer
-            out = np.empty(meta.shape, dtype=dt)
-            try:
-                n, calls = preadv_full(self._fd, [_byte_view(out)], meta.offset)
-            except CorruptFileError:
-                raise CorruptFileError(f"short read on {name}") from None
-            READ_COUNTER.add(n, calls)
+        with TRACER.phase(SPAN_TH5_READ, bytes=meta.nbytes):
+            dt = meta.np_dtype
+            if meta.is_chunked:
+                out = np.empty(meta.shape, dtype=dt.newbyteorder("="))
+                self._gather_rows_chunked(name, meta, 0, meta.n_rows, out, verify=verify)
+                return out
+            if self._is_native(dt):
+                # vectored read straight into the result array — no intermediate
+                # bytes object between the page cache and the caller's buffer
+                out = np.empty(meta.shape, dtype=dt)
+                try:
+                    n, calls = preadv_full(self._fd, [_byte_view(out)], meta.offset)
+                except CorruptFileError:
+                    raise CorruptFileError(f"short read on {name}") from None
+                READ_COUNTER.add(n, calls)
+                if verify and meta.crc32 is not None:
+                    with TRACER.phase(SPAN_TH5_VERIFY, bytes=out.nbytes):
+                        if (zlib.crc32(_byte_view(out)) & 0xFFFFFFFF) != meta.crc32:
+                            raise CorruptFileError(f"payload CRC mismatch on {name}")
+                return out
+            # foreign-endian fallback: read raw, byteswap to native
+            raw = os.pread(self._fd, meta.nbytes, meta.offset)
+            READ_COUNTER.add(len(raw), 1)
+            if len(raw) != meta.nbytes:
+                raise CorruptFileError(f"short read on {name}")
             if verify and meta.crc32 is not None:
-                if (zlib.crc32(_byte_view(out)) & 0xFFFFFFFF) != meta.crc32:
-                    raise CorruptFileError(f"payload CRC mismatch on {name}")
-            return out
-        # foreign-endian fallback: read raw, byteswap to native
-        raw = os.pread(self._fd, meta.nbytes, meta.offset)
-        READ_COUNTER.add(len(raw), 1)
-        if len(raw) != meta.nbytes:
-            raise CorruptFileError(f"short read on {name}")
-        if verify and meta.crc32 is not None:
-            if (zlib.crc32(raw) & 0xFFFFFFFF) != meta.crc32:
-                raise CorruptFileError(f"payload CRC mismatch on {name}")
-        arr = np.frombuffer(raw, dtype=dt)
-        arr = arr.astype(arr.dtype.newbyteorder("="))
-        return arr.reshape(meta.shape)
+                with TRACER.phase(SPAN_TH5_VERIFY, bytes=len(raw)):
+                    if (zlib.crc32(raw) & 0xFFFFFFFF) != meta.crc32:
+                        raise CorruptFileError(f"payload CRC mismatch on {name}")
+            arr = np.frombuffer(raw, dtype=dt)
+            arr = arr.astype(arr.dtype.newbyteorder("="))
+            return arr.reshape(meta.shape)
 
     def read_rows_into(
         self,
@@ -1557,7 +1561,10 @@ class TH5File:
         """Durably publish the current tree: append index, flip superblock.
         Returns the new generation."""
         self._check_writable()
-        return self._commit()
+        with TRACER.phase(SPAN_CKPT_COMMIT) as commit:
+            gen = self._commit()
+            commit.tag("generation", gen)
+        return gen
 
     def _commit(self) -> int:
         self._index.generation += 1
@@ -1566,12 +1573,14 @@ class TH5File:
             idx_off = align_up(self._file_end, self.block_size)
             self._file_end = idx_off + len(blob)
         pwrite_full(self._fd, blob, idx_off)
-        os.fsync(self._fd)  # order: data+index durable before the flip
+        with TRACER.phase(SPAN_CKPT_FSYNC):
+            os.fsync(self._fd)  # order: data+index durable before the flip
         sb = _pack_superblock(
             self.block_size, idx_off, len(blob), self._file_end, self._index.generation, self._created
         )
         pwrite_full(self._fd, sb, 0)
-        os.fsync(self._fd)
+        with TRACER.phase(SPAN_CKPT_FSYNC):
+            os.fsync(self._fd)
         self._dirty = False
         # the committed index supersedes every journaled commit-mark: reset
         # the sidecar so the next interval starts empty (a crash between the

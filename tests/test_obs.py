@@ -196,6 +196,67 @@ def test_disabled_tracer_identity_and_zero_allocation():
     assert delta < 20, f"disabled-tracer hot path allocated {delta} blocks over 1000 spans"
 
 
+def test_phase_nests_tags_and_records_only_when_enabled():
+    tr = Tracer(enabled=True)
+    with tr.phase("ckpt.save", step=7) as save:
+        with tr.phase("ckpt.write", bytes=64):
+            pass
+        with tr.phase("ckpt.commit") as commit:
+            commit.tag("generation", 3)
+        save.tag("bytes", 64)
+    spans = {s.name: s for s in tr.snapshot()}
+    assert list(spans) == ["ckpt.write", "ckpt.commit", "ckpt.save"]
+    root = spans["ckpt.save"]
+    assert root.parent_id == 0 and root.tags == {"step": 7, "bytes": 64}
+    assert spans["ckpt.write"].tags == {"bytes": 64}
+    assert spans["ckpt.commit"].tags == {"generation": 3}
+    assert {spans["ckpt.write"].parent_id, spans["ckpt.commit"].parent_id} == {root.span_id}
+    assert len({s.trace_id for s in spans.values()}) == 1
+    assert tr.current_context() is None  # the ambient context is restored
+
+    # an ambient context (a traced request) is the outermost phase's parent
+    req = tr.start_trace("broker.request")
+    with tr.use(req):
+        with tr.phase("th5.read", bytes=8):
+            pass
+    req.end()
+    read = [s for s in tr.snapshot() if s.name == "th5.read"][0]
+    assert read.trace_id == req.trace_id and read.parent_id == req.span_id
+
+    # an unsampled root: its nested phases start no roots of their own
+    tr = Tracer(enabled=True, sample_every=2)
+    tr.start_trace("taken").end()
+    with tr.phase("sim.snapshot"):
+        with tr.phase("sim.fetch"):
+            pass
+    assert [s.name for s in tr.snapshot()] == ["taken"]
+
+    # disabled: no ring entry, tags still accepted
+    tr = Tracer()
+    with tr.phase("ckpt.save", step=1) as save:
+        save.tag("bytes", 1)
+        with tr.phase("ckpt.write"):
+            pass
+    assert len(tr) == 0 and tr.current_context() is None
+
+
+def test_phase_imports_no_jax():
+    """The container and the service use phases without importing JAX."""
+    from tests._subproc import run_with_devices
+
+    out = run_with_devices(
+        "import sys\n"
+        "from repro.core.container import TH5File\n"
+        "from repro.obs.trace import TRACER\n"
+        "from repro.service import DataService\n"
+        "with TRACER.phase('th5.read', bytes=1) as p:\n"
+        "    p.tag('bytes', 2)\n"
+        "print('jax' in sys.modules)\n",
+        1,
+    )
+    assert out.strip() == "False"
+
+
 # -- metrics registry ----------------------------------------------------------
 
 
