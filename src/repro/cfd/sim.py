@@ -12,8 +12,10 @@ sliding window.  Rollback/branching delegates to ``core.steering``.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -28,9 +30,25 @@ from ..obs.trace import (
     TRACER,
 )
 from .projection import FluidConfig, make_step
-from .spacetree import TreeLayout, to_blocked, topology_arrays
+from .spacetree import TreeLayout, topology_arrays
 
 FIELDS = ("u", "v", "p", "T")
+
+
+@partial(jax.jit, static_argnames=("gx", "gy", "n", "dtype"))
+def stage_rows(fields: tuple, gx: int, gy: int, n: int, dtype=None) -> jax.Array:
+    """(gx·n, gy·n) fields → (G, n²·F) rows in the file's row order.
+
+    Row ``g`` is d-grid ``g`` in ``to_blocked``'s order; element
+    ``[g, c·F + f]`` is field ``f`` at interior cell ``c`` (row-major in
+    the d-grid), so the rows are the fields interleaved cell by cell.  The
+    minor dimension is the whole row, so the copy to the host arrives
+    C-ordered and needs no reordering there.
+    """
+    x = jnp.stack(fields, axis=-1)
+    if dtype is not None:
+        x = x.astype(dtype)
+    return x.reshape(gx, n, gy, n, len(fields)).transpose(0, 2, 1, 3, 4).reshape(gx * gy, -1)
 
 
 @dataclass
@@ -67,15 +85,18 @@ class Simulation:
 
     # -- the paper's output layout ---------------------------------------------------
 
+    def _stage(self, fields: tuple, dtype=None) -> np.ndarray:
+        """The fields' (G, n²·F) rows, built on the device and fetched."""
+        lay = self.layout
+        with TRACER.phase(SPAN_SIM_FETCH) as fetch:
+            rows = np.asarray(stage_rows(fields, gx=lay.gx, gy=lay.gy, n=lay.n, dtype=dtype))
+            fetch.tag("bytes", rows.nbytes)
+        return rows
+
     def _pack_cells(self) -> np.ndarray:
         """Blocked (G, n², n_fields) cell rows — the linear write buffer."""
-        blocks = []
-        with TRACER.phase(SPAN_SIM_FETCH) as fetch:
-            for f in FIELDS:
-                b = to_blocked(self.layout, self.state[f])[:, 1:-1, 1:-1]
-                blocks.append(np.asarray(b).reshape(self.layout.G, -1))
-            fetch.tag("bytes", sum(b.nbytes for b in blocks))
-        return np.stack(blocks, axis=-1)  # (G, n², F)
+        cells = self._stage(tuple(self.state[f] for f in FIELDS))
+        return cells.reshape(self.layout.G, self.layout.n**2, len(FIELDS))  # a view
 
     def snapshot(self) -> int:
         with TRACER.phase(SPAN_SIM_SNAPSHOT) as snap:
@@ -84,11 +105,7 @@ class Simulation:
             snap.tag("step", step)
             cells = self._pack_cells()
             prev = self._prev_cells if self._prev_cells is not None else cells
-            with TRACER.phase(SPAN_SIM_FETCH) as fetch:
-                ct = np.asarray(
-                    to_blocked(self.layout, self.state["cell_type"].astype(jnp.float32))[:, 1:-1, 1:-1]
-                ).astype(np.int8).reshape(self.layout.G, -1)
-                fetch.tag("bytes", ct.nbytes)
+            ct = self._stage((self.state["cell_type"],), dtype=jnp.int8)  # (G, n²)
             with TRACER.phase(SPAN_SIM_TOPOLOGY, grids=self.layout.G):
                 uids, subgrid, boxes, rank_of = topology_arrays(self.layout, self.n_ranks)
             self.manager.save(

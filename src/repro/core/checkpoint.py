@@ -344,10 +344,13 @@ class CheckpointManager:
                 plans: dict[str, Any] = {}
                 chunked: dict[str, str] = {}  # leaf path -> resolved codec
                 total_bytes = 0
+                copy_bytes = 0
                 # C order for the pwrites: a leaf staged in another order is copied here
                 with TRACER.phase(SPAN_CKPT_PLAN) as planning:
-                    for path, arr in leaves.items():
-                        arr = np.asarray(arr, order="C")  # NB: ascontiguousarray would 0-d → (1,)
+                    for path, staged in leaves.items():
+                        arr = np.asarray(staged, order="C")  # NB: ascontiguousarray would 0-d → (1,)
+                        if arr is not staged:
+                            copy_bytes += arr.nbytes
                         leaves[path] = arr
                         name = f"{group}/state/{path}"
                         codec = codec_policy.resolve(path, arr) if codec_policy else "none"
@@ -367,7 +370,7 @@ class CheckpointManager:
                         plan = self._plan_for(n_rows, meta.row_bytes, n_ranks)
                         metas[path], plans[path] = meta, plan
                         total_bytes += arr.nbytes
-                    planning.tag("bytes", total_bytes)
+                    planning.tag("bytes", total_bytes).tag("copy_bytes", copy_bytes)
 
                 # ---- independent writes into disjoint extents ----
                 reqs: list[list[WriteRequest]] = [[] for _ in range(n_ranks)]

@@ -235,3 +235,28 @@ def test_zero_d_device_array_restores_as_array(tmp_path):
     assert got["step"].shape == () and int(got["step"]) == 7
     assert got["t"] == 0.5 and isinstance(got["t"], float)
     assert got["n"] == 3 and isinstance(got["n"], int)
+
+
+def test_plan_counts_the_bytes_it_copies_into_c_order(tmp_path):
+    """``ckpt.plan`` tags ``copy_bytes`` with the leaves planning had to
+    copy into C order: a Fortran-ordered leaf counts, a C-ordered one does
+    not, and both read back as saved."""
+    from repro.obs import TRACER
+
+    fortran = np.asfortranarray(np.arange(48 * 16, dtype=np.float32).reshape(48, 16))
+    c = np.arange(64, dtype=np.int32).reshape(8, 8)
+    assert not fortran.flags.c_contiguous and c.flags.c_contiguous
+    TRACER.configure(enabled=True, sample_every=1)
+    TRACER.reset()
+    try:
+        with CheckpointManager(str(tmp_path / "f.th5")) as mgr:
+            mgr.save(0, {"f": fortran, "c": c}, n_ranks=2)
+            _, got = mgr.restore(0)
+        ring = TRACER.drain()
+    finally:
+        TRACER.configure(enabled=False)
+        TRACER.reset()
+    (plan,) = [s for s in ring if s.name == "ckpt.plan"]
+    assert plan.tags == {"bytes": fortran.nbytes + c.nbytes, "copy_bytes": fortran.nbytes}
+    np.testing.assert_array_equal(got["f"], fortran)
+    np.testing.assert_array_equal(got["c"], c)

@@ -111,8 +111,10 @@ def test_bytes_tags_are_the_datasets_sizes(traced):
     step, sim, mgr, events, _, sizes = traced
     tags = lambda name: [t for n, _, _, t in events if n == name]  # noqa: E731
     state_bytes = sum(sizes.values())
-    for name in ("ckpt.save", "ckpt.plan", "ckpt.write", "ckpt.seal"):
+    for name in ("ckpt.save", "ckpt.write", "ckpt.seal"):
         assert tags(name) == [{**({"step": step} if name == "ckpt.save" else {}), "bytes": state_bytes}]
+    # the snapshot stages every leaf C-ordered: planning copies nothing
+    assert tags("ckpt.plan") == [{"bytes": state_bytes, "copy_bytes": 0}]
     # the step counter, the four fields, the cell types
     assert sorted(t["bytes"] for t in tags("sim.fetch")) == sorted(
         [sim.state["t"].nbytes, sizes["current_cell_data"], sizes["cell_type"]]

@@ -73,3 +73,16 @@ def test_flash_attention_compiles_for_v5e_at_qwen3_head_dim(one_chip):
     # qwen3-8b: 32 heads of head_dim 128, one sequence of 4096 tokens
     q = jax.ShapeDtypeStruct((32, 4096, 128), jnp.bfloat16, sharding=one_chip)
     assert "tpu_custom_call" in _compiled_hlo(flash_attention, q, q, q)
+
+
+@pytest.mark.parametrize("dtype, n_fields", [(jnp.float32, 4), (jnp.int8, 1)], ids=["cells", "cell_type"])
+def test_snapshot_rows_leave_the_v5e_row_major(one_chip, dtype, n_fields):
+    """The snapshot cell's rows (3072 x 12288 cells, d-grids of 16²) come
+    out of the device pack with the row as the minor dimension, so their
+    copy to the host is C-ordered and needs no reordering there."""
+    from repro.cfd.sim import stage_rows
+
+    field = jax.ShapeDtypeStruct((3072, 12288), dtype, sharding=one_chip)
+    compiled = stage_rows.lower((field,) * n_fields, gx=192, gy=768, n=16, dtype=dtype).compile()
+    assert compiled.out_info.shape == (192 * 768, 256 * n_fields)
+    assert compiled.output_formats.layout.major_to_minor == (0, 1)
