@@ -172,15 +172,9 @@ def qwen3_one_chip_share():
     """qwen3-8b at its published widths: depth cut to 2 layers (whole periods
     of its uniform pattern) and one eighth of the vocabulary, the share of one
     chip in an 8-way vocabulary-parallel layer."""
-    from repro.configs import get_config
-    from repro.models.common import LayerSpec, uniform_stages
+    from repro.configs import qwen3_8b
 
-    full = get_config("qwen3-8b")
-    return full.scaled(
-        n_layers=2,
-        stages=uniform_stages(2, LayerSpec("attn", "mlp")),
-        vocab_size=full.vocab_size // 8,
-    )
+    return qwen3_8b.share(n_layers=2, vocab_div=8)
 
 
 def _trainer(cfg, data, path: str, every: int, mesh=None):

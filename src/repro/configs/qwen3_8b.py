@@ -22,6 +22,18 @@ def config() -> ModelConfig:
     )
 
 
+def share(n_layers: int, vocab_div: int) -> ModelConfig:
+    """The published widths, cut to ``n_layers`` (one pipeline stage of the
+    uniform pattern) and to 1/``vocab_div`` of the vocabulary (one chip's
+    slice of a vocabulary-parallel head)."""
+    full = config()
+    return full.scaled(
+        n_layers=n_layers,
+        stages=uniform_stages(n_layers, LayerSpec("attn", "mlp")),
+        vocab_size=full.vocab_size // vocab_div,
+    )
+
+
 def smoke_config() -> ModelConfig:
     return ModelConfig(
         name="qwen3-smoke",

@@ -34,6 +34,8 @@ from repro.obs.trace import (
     SPAN_CKPT_RESTORE,
     SPAN_CKPT_SAVE,
     SPAN_CKPT_SEAL,
+    SPAN_CKPT_STAGE,
+    SPAN_CKPT_WAIT,
     SPAN_CKPT_WRITE,
     TRACER,
 )
@@ -556,11 +558,11 @@ class AsyncCheckpointer:
 
     def save(self, step: int, state: Any, **kw) -> None:
         if self.double_buffer:
-            staged = _stage_to_host(state)  # overlaps the in-flight write
+            staged = self._stage(state)  # overlaps the in-flight write
             self.wait()
         else:
             self.wait()
-            staged = _stage_to_host(state)
+            staged = self._stage(state)
 
         def run() -> None:
             try:
@@ -571,10 +573,24 @@ class AsyncCheckpointer:
         self._thread = threading.Thread(target=run, name=f"ckpt-save-{step}", daemon=True)
         self._thread.start()
 
+    @staticmethod
+    def _stage(state: Any) -> Any:
+        import jax  # the one place this module meets device arrays
+
+        with TRACER.phase(SPAN_CKPT_STAGE) as staging:
+            staged = _stage_to_host(state)
+            # gathered: device arrays held by more than one device, each
+            # gathered into one host buffer
+            gathered = [x for x in jax.tree.leaves(state) if len(getattr(getattr(x, "sharding", None), "device_set", ())) > 1]
+            staging.tag("bytes", sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(staged)))
+            staging.tag("gathered_bytes", sum(x.nbytes for x in gathered))
+        return staged
+
     def wait(self) -> SaveResult | None:
-        if self._thread is not None:
-            self._thread.join()
-            self._thread = None
+        with TRACER.phase(SPAN_CKPT_WAIT):  # opened with no write in flight too: a wait of 0
+            if self._thread is not None:
+                self._thread.join()
+                self._thread = None
         if self._error is not None:
             err, self._error = self._error, None
             raise err

@@ -294,7 +294,7 @@ def _remat_wrap(fn, cfg: ModelConfig):
     if cfg.remat == "dots":
         policy = jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
         return jax.checkpoint(fn, policy=policy)
-    return jax.checkpoint(fn)  # "full"
+    return jax.checkpoint(fn)  # "full": and each query chunk of attention (attention._attend)
 
 
 def hidden_states(

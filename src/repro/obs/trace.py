@@ -77,6 +77,8 @@ SPAN_CKPT_FSYNC = "ckpt.fsync"  # one os.fsync of the commit
 SPAN_CKPT_RESTORE = "ckpt.restore"  # CheckpointManager.restore
 SPAN_TH5_READ = "th5.read"  # TH5File.read of one dataset
 SPAN_TH5_VERIFY = "th5.verify"  # its payload CRC32
+SPAN_CKPT_STAGE = "ckpt.stage"  # AsyncCheckpointer: device-to-host staging
+SPAN_CKPT_WAIT = "ckpt.wait"  # AsyncCheckpointer: join of the in-flight write
 
 PHASE_SPANS = (
     SPAN_SIM_SNAPSHOT,
@@ -93,6 +95,8 @@ PHASE_SPANS = (
     SPAN_CKPT_RESTORE,
     SPAN_TH5_READ,
     SPAN_TH5_VERIFY,
+    SPAN_CKPT_STAGE,
+    SPAN_CKPT_WAIT,
 )
 
 

@@ -86,3 +86,22 @@ def test_snapshot_rows_leave_the_v5e_row_major(one_chip, dtype, n_fields):
     compiled = stage_rows.lower((field,) * n_fields, gx=192, gy=768, n=16, dtype=dtype).compile()
     assert compiled.out_info.shape == (192 * 768, 256 * n_fields)
     assert compiled.output_formats.layout.major_to_minor == (0, 1)
+
+
+def test_attention_backward_keeps_one_query_chunk_at_qwen3_widths(one_chip):
+    """The gradient of chunked attention under ``remat="full"`` recomputes
+    each 1024-query chunk's scores instead of keeping them all: at qwen3-8b's heads and 2 x 4096
+    tokens it needs less than one whole 4096 x 4096 float32 score matrix
+    (2.65 GB against 10.07 GB when every chunk's scores are kept)."""
+    from repro.models.attention import _attend
+
+    B, S, H, KV, D = 2, 4096, 32, 8, 128
+    q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, S, KV, D), jnp.bfloat16, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, p):
+        return jnp.sum(_attend(q, k, v, p, p, 0, remat_chunks=True).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv, pos).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < B * H * S * S * 4
