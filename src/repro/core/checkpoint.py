@@ -20,16 +20,20 @@ addressable → offline sliding window + time-reversible steering.
 from __future__ import annotations
 
 import fnmatch
+import functools
 import json
 import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Mapping, Sequence
 
 import numpy as np
 
 from repro.obs.trace import (
+    SPAN_CKPT_ASSEMBLE,
+    SPAN_CKPT_FETCH,
     SPAN_CKPT_PLAN,
     SPAN_CKPT_RESTORE,
     SPAN_CKPT_SAVE,
@@ -538,16 +542,17 @@ class AsyncCheckpointer:
     """Overlap snapshots with compute (paper §1: during the dump 'all
     processes ... have to wait' — we remove that wait).
 
-    ``save`` stages device arrays to host synchronously (cheap, and required
-    before the step buffer is donated/overwritten) and runs the pwrite +
-    commit on a background thread.  At most one snapshot is in flight.
+    ``save`` stages device arrays to host synchronously (required before the
+    step buffer is donated/overwritten) and runs the pwrite + commit on a
+    background thread.  At most one snapshot is in flight.
 
     **Double-buffered mode** (default, paper §5.2 "asynchronous I/O"): the
     device→host staging of step *n+1* overlaps the disk write of step *n* —
     two staging buffers are alive at the peak (the in-flight one and the one
     being filled).  ``double_buffer=False`` restores the seed behaviour of
     joining the in-flight write *before* staging (single buffer, no
-    stage/write overlap)."""
+    stage/write overlap).  Each save stages into host buffers of its own,
+    which its write alone reads."""
 
     def __init__(self, manager: CheckpointManager, *, double_buffer: bool = True):
         self.manager = manager
@@ -575,16 +580,8 @@ class AsyncCheckpointer:
 
     @staticmethod
     def _stage(state: Any) -> Any:
-        import jax  # the one place this module meets device arrays
-
         with TRACER.phase(SPAN_CKPT_STAGE) as staging:
-            staged = _stage_to_host(state)
-            # gathered: device arrays held by more than one device, each
-            # gathered into one host buffer
-            gathered = [x for x in jax.tree.leaves(state) if len(getattr(getattr(x, "sharding", None), "device_set", ())) > 1]
-            staging.tag("bytes", sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(staged)))
-            staging.tag("gathered_bytes", sum(x.nbytes for x in gathered))
-        return staged
+            return _stage_to_host(state, staging)
 
     def wait(self) -> SaveResult | None:
         with TRACER.phase(SPAN_CKPT_WAIT):  # opened with no write in flight too: a wait of 0
@@ -597,17 +594,57 @@ class AsyncCheckpointer:
         return self._last_result
 
 
-def _stage_to_host(tree: Any) -> Any:
-    def stage(x):
-        if hasattr(x, "addressable_data") or type(x).__module__.startswith("jax"):
-            return np.asarray(x)
-        if isinstance(x, np.ndarray):
-            return x.copy()
-        return x
+def _on_device(x: Any) -> bool:
+    return hasattr(x, "addressable_data") or type(x).__module__.startswith("jax")
 
-    if isinstance(tree, dict):
-        return {k: _stage_to_host(v) for k, v in tree.items()}
-    if isinstance(tree, (list, tuple)):
-        t = type(tree)
-        return t(_stage_to_host(v) for v in tree)
-    return stage(tree)
+
+def _n_devices(x: Any) -> int:
+    return len(getattr(getattr(x, "sharding", None), "device_set", ()))
+
+
+@functools.cache
+def _assembly_pool() -> ThreadPoolExecutor:
+    # numpy releases the GIL in the assembly's block copies: a thread a core
+    return ThreadPoolExecutor(max_workers=min(32, os.cpu_count() or 1), thread_name_prefix="ckpt-assemble")
+
+
+def _copy_into(copy: tuple) -> None:
+    buffer, index, shard = copy
+    buffer[index] = shard
+
+
+def _stage_to_host(tree: Any, staging) -> Any:
+    """``tree`` with every leaf copied to the host; tags ``staging`` with the
+    bytes staged (``bytes``) and those of the leaves gathered from more than
+    one device (``gathered_bytes``).
+
+    Every device copy is put in flight before the first wait (``ckpt.fetch``).
+    A leaf held by more than one device is then assembled from its distinct
+    shards, on a thread pool, into a fresh C-ordered buffer
+    (``ckpt.assemble``).  A leaf on one device is its single host copy; a
+    host array is copied; anything else passes through."""
+    import jax  # the one place this module meets device arrays
+
+    leaves, treedef = jax.tree.flatten(tree)
+    device = [i for i, x in enumerate(leaves) if _on_device(x)]
+    # a multi-device leaf's distinct shards: (its slice of the leaf, its array)
+    shards = {
+        i: [(s.index, s.data) for s in leaves[i].addressable_shards if s.replica_id == 0]
+        for i in device
+        if _n_devices(leaves[i]) > 1 and leaves[i].is_fully_addressable
+    }
+    with TRACER.phase(SPAN_CKPT_FETCH):
+        for i in device:  # every copy in flight before the first wait
+            for _, data in shards.get(i, [(None, leaves[i])]):
+                data.copy_to_host_async()
+        host = {i: np.asarray(leaves[i]) for i in device if i not in shards}
+        fetched = {i: [(index, np.asarray(data)) for index, data in parts] for i, parts in shards.items()}
+    with TRACER.phase(SPAN_CKPT_ASSEMBLE):
+        host.update((i, np.empty(leaves[i].shape, leaves[i].dtype)) for i in shards)
+        copies = [(host[i], index, part) for i, parts in fetched.items() for index, part in parts]
+        if copies:
+            list(_assembly_pool().map(_copy_into, copies))
+    staged = [host[i] if i in host else x.copy() if isinstance(x, np.ndarray) else x for i, x in enumerate(leaves)]
+    staging.tag("bytes", sum(getattr(x, "nbytes", 0) for x in staged))
+    staging.tag("gathered_bytes", sum(host[i].nbytes for i in shards))
+    return jax.tree.unflatten(treedef, staged)

@@ -79,6 +79,8 @@ SPAN_TH5_READ = "th5.read"  # TH5File.read of one dataset
 SPAN_TH5_VERIFY = "th5.verify"  # its payload CRC32
 SPAN_CKPT_STAGE = "ckpt.stage"  # AsyncCheckpointer: device-to-host staging
 SPAN_CKPT_WAIT = "ckpt.wait"  # AsyncCheckpointer: join of the in-flight write
+SPAN_CKPT_FETCH = "ckpt.fetch"  # staging: every shard's device-to-host copy
+SPAN_CKPT_ASSEMBLE = "ckpt.assemble"  # staging: shards copied into the leaves' buffers
 
 PHASE_SPANS = (
     SPAN_SIM_SNAPSHOT,
@@ -97,6 +99,8 @@ PHASE_SPANS = (
     SPAN_TH5_VERIFY,
     SPAN_CKPT_STAGE,
     SPAN_CKPT_WAIT,
+    SPAN_CKPT_FETCH,
+    SPAN_CKPT_ASSEMBLE,
 )
 
 

@@ -39,8 +39,8 @@ for double_buffer in (True, False):
     ring = TRACER.drain()
     TRACER.configure(enabled=False)
     TRACER.reset()
-    mine = sorted((s for s in ring if s.name in ("ckpt.stage", "ckpt.wait")), key=lambda s: s.t0)
-    out[str(double_buffer)] = [[s.name, s.tags or {}, s.parent_id] for s in mine]
+    mine = sorted((s for s in ring if s.name in ("ckpt.stage", "ckpt.fetch", "ckpt.assemble", "ckpt.wait")), key=lambda s: s.t0)
+    out[str(double_buffer)] = [[s.name, s.tags or {}, s.parent_id, s.span_id] for s in mine]
 print(json.dumps(out))
 """
 
@@ -55,7 +55,7 @@ SPLIT, ROWS, REPLICATED, ONE_DEVICE, HOST = 64 * 8 * 4, 16 * 4 * 2, 5 * 4, 3 * 3
 
 @pytest.mark.parametrize("double_buffer", ["True", "False"])
 def test_stage_is_tagged_with_bytes_staged_and_gathered(spans, double_buffer):
-    stages = [tags for name, tags, _ in spans[double_buffer] if name == "ckpt.stage"]
+    stages = [tags for name, tags, *_ in spans[double_buffer] if name == "ckpt.stage"]
     assert len(stages) == 2
     for tags in stages:
         assert tags["bytes"] == SPLIT + ROWS + REPLICATED + ONE_DEVICE + HOST
@@ -63,13 +63,23 @@ def test_stage_is_tagged_with_bytes_staged_and_gathered(spans, double_buffer):
         assert tags["gathered_bytes"] == SPLIT + ROWS + REPLICATED
 
 
+STAGE = ["ckpt.stage", "ckpt.fetch", "ckpt.assemble"]
+
+
 @pytest.mark.parametrize("double_buffer,order", [
-    ("True", ["ckpt.stage", "ckpt.wait", "ckpt.stage", "ckpt.wait", "ckpt.wait"]),
-    ("False", ["ckpt.wait", "ckpt.stage", "ckpt.wait", "ckpt.stage", "ckpt.wait"]),
+    ("True", [*STAGE, "ckpt.wait", *STAGE, "ckpt.wait", "ckpt.wait"]),
+    ("False", ["ckpt.wait", *STAGE, "ckpt.wait", *STAGE, "ckpt.wait"]),
 ])
 def test_wait_joins_the_write_in_flight_after_or_before_staging(spans, double_buffer, order):
     """Double-buffered, each save stages and then joins the write before it;
     single-buffered, it joins first.  Each is a phase of its own, and the
-    last is the caller's ``wait()``."""
-    assert [name for name, _, _ in spans[double_buffer]] == order
-    assert all(parent == 0 for _, _, parent in spans[double_buffer])
+    last is the caller's ``wait()``.  Staging's fetch and assembly nest in
+    its own span."""
+    assert [name for name, *_ in spans[double_buffer]] == order
+    stage_id = None
+    for name, _, parent, span_id in spans[double_buffer]:
+        if name in ("ckpt.stage", "ckpt.wait"):
+            assert parent == 0
+            stage_id = span_id if name == "ckpt.stage" else None
+        else:
+            assert stage_id is not None and parent == stage_id

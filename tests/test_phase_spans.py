@@ -35,8 +35,8 @@ PARENT = {
     "sim.layout": "sim.load",
 }
 # AsyncCheckpointer's own phases, which a snapshot does not open
-# (tests/test_async_stage_spans.py)
-ASYNC_SAVER = {"ckpt.stage", "ckpt.wait"}
+# (tests/test_async_stage_spans.py, tests/test_async_stage_gather.py)
+ASYNC_SAVER = {"ckpt.stage", "ckpt.wait", "ckpt.fetch", "ckpt.assemble"}
 
 
 @pytest.fixture()
