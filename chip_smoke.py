@@ -16,9 +16,9 @@ is killed before its next save, and a second ``Trainer`` resumes from the
 file: the resumed state must equal the saved one bit for bit, and its losses
 those of the uninterrupted run.
 
-``--chips 4``: the on-device planner and aggregator gather on a 4-chip mesh
-against the host planner and numpy, and the train phase on a 2×2
-(data, model) mesh against the same steps on one chip.
+``--chips 4``: the train phase on a 2×2 (data, model) mesh, whose saves
+gather every shard of the state to the host, against the same steps on one
+chip.
 
 Every phase prints one JSON line; the last line is the verdict
 ``{"ok": true, "device": {...}}``.  Without a TPU the script exits non-zero
@@ -328,53 +328,6 @@ def train_phase(workdir: str, cfg, data, *, save_at: int = 3, kill_at: int = 5, 
     return out
 
 
-# -- four chips ------------------------------------------------------------------
-
-
-def collective_phase(rows_per_shard: int = 16384, cols: int = 256) -> dict:
-    from jax.sharding import NamedSharding
-    from jax.sharding import PartitionSpec as P
-
-    from repro.core.collective_io import collective_plan, gather_to_aggregators
-    from repro.core.hyperslab import exclusive_prefix_sum
-    from repro.launch.mesh import make_mesh
-
-    n = len(jax.devices())
-    mesh = make_mesh((n,), ("io",))
-    rng = np.random.default_rng(SEED)
-    counts = rng.integers(0, 1 << 20, n).astype(np.int32)
-    total, starts = collective_plan(mesh, "io", counts)
-    check(total == int(counts.sum()), f"device total {total} != {counts.sum()}")
-    check(np.array_equal(starts, exclusive_prefix_sum(counts)), f"device starts {starts}")
-
-    # each shard holds its d-grid rows; two aggregators gather their halves
-    n_agg = 2
-    group = n // n_agg
-    x = rng.standard_normal((n * rows_per_shard, cols), dtype=np.float32)
-    xs = jax.device_put(x, NamedSharding(mesh, P("io")))
-    t = time.perf_counter()
-    g = gather_to_aggregators(mesh, "io", n_agg, xs)
-    g.block_until_ready()
-    first_s = time.perf_counter() - t
-    t = time.perf_counter()
-    gather_to_aggregators(mesh, "io", n_agg, xs).block_until_ready()
-    gather_s = time.perf_counter() - t
-    want_rows = group * rows_per_shard
-    for shard in g.addressable_shards:
-        i = shard.index[0].start // want_rows
-        lo = (i // group) * want_rows
-        check(same_bits(np.asarray(shard.data), x[lo : lo + want_rows]), f"shard {i} gathered wrong rows")
-    return {
-        "phase": "collective",
-        "shards": n,
-        "plan_matches_host": True,
-        "gather_bytes": int(g.nbytes),
-        "gather_first_call_s": first_s,
-        "gather_s": gather_s,
-        "gather_matches_numpy": True,
-    }
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
@@ -404,7 +357,6 @@ def main() -> int:
         else:
             from repro.launch.mesh import make_mesh
 
-            log(**collective_phase())
             ref = reference_losses(cfg, data, workdir, steps=5)
             res = train_phase(workdir, cfg, data, mesh=make_mesh((2, 2), ("data", "model")))
             worst = max(abs(a - b) / abs(b) for a, b in zip(res["losses"], ref))

@@ -9,12 +9,12 @@ buffering routes all data through *aggregator* nodes sitting on those links:
      network while the I/O links are utilised to their full extent."
 
 TPU adaptation: every TPU host owns the PCIe/NIC path for its local devices;
-"aggregation over the fast network" becomes (a) on-device gathers along mesh
-axes onto aggregator shards (see ``collective_io.gather_to_aggregators``)
-and (b) the host-side coalescing implemented here: N logical ranks hand
-their disjoint extents to A aggregators; each aggregator merges adjacent
-extents into maximal contiguous runs and issues few, large ``pwritev`` calls
-instead of many small ones.  Because the hyperslab planner orders extents by
+the state reaches the host staged (``checkpoint._stage_to_host`` gathers a
+sharded tree's distinct shards), and "aggregation" is the host-side
+coalescing implemented here: N logical ranks hand their disjoint extents to
+A aggregators; each aggregator merges adjacent extents into maximal
+contiguous runs and issues few, large ``pwritev`` calls instead of many
+small ones.  Because the hyperslab planner orders extents by
 rank, a contiguous rank-group's extents always coalesce into exactly one run
 per dataset — the best case the paper engineered for.
 

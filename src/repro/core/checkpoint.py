@@ -21,13 +21,12 @@ from __future__ import annotations
 
 import fnmatch
 import functools
-import json
 import os
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Any, Callable, Mapping, Sequence
+from typing import Any, Mapping
 
 import numpy as np
 
@@ -300,10 +299,7 @@ class CheckpointManager:
         *,
         n_ranks: int = 1,
         aggregation: AggregationConfig | None = None,
-        independent: bool = False,
-        checksum: bool = True,
         extra_attrs: Mapping[str, Any] | None = None,
-        extra_datasets: Mapping[str, np.ndarray] | None = None,
         topology_override: tuple | None = None,
         overwrite: bool = False,
         codec_policy: CodecPolicy | None = None,
@@ -312,7 +308,14 @@ class CheckpointManager:
 
         ``n_ranks`` models the SPMD writer count: every leaf's rows are split
         contiguously over ranks (reduce+exscan plan) and written as disjoint
-        hyperslabs through the collective-buffering writer.
+        hyperslabs through the collective-buffering writer (``aggregation``
+        sets its aggregators; ``None`` is the manager's default).  Each
+        unchunked leaf is sealed with its CRC32 before the commit.
+
+        ``extra_attrs`` are added to the step group's attributes;
+        ``topology_override`` is the (uids, subgrid, boxes) topology written
+        in place of the per-leaf one; ``overwrite`` replaces a step already
+        in the file.
 
         ``codec_policy`` routes selected leaves through the chunked filter
         pipeline instead (compressed, variable-length chunks written by the
@@ -393,9 +396,7 @@ class CheckpointManager:
                             )
                 writer = self._writer_for(aggregation)
                 with TRACER.phase(SPAN_CKPT_WRITE, bytes=total_bytes):
-                    stats = (
-                        writer.write_independent(reqs) if independent else writer.write_collective(reqs)
-                    )
+                    stats = writer.write_collective(reqs)
 
                     # ---- chunked leaves: encode in the aggregators, overlapped ----
                     fstats = FilterStats()
@@ -417,17 +418,11 @@ class CheckpointManager:
                 else:
                     self._write_topology(group, metas, plans, n_ranks)
 
-                for name, arr in dict(extra_datasets or {}).items():
-                    arr = np.ascontiguousarray(arr)
-                    meta = self.file.create_dataset(f"{group}/{name}", arr.shape, arr.dtype)
-                    self.file.write_full(meta, arr, checksum=checksum)
-
-                if checksum:
-                    # chunked leaves carry per-chunk CRCs
-                    sealed = [path for path in leaves if path not in chunked]
-                    with TRACER.phase(SPAN_CKPT_SEAL, bytes=sum(leaves[path].nbytes for path in sealed)):
-                        for path in sealed:
-                            self.file.seal_checksum(f"{group}/state/{path}")
+                # chunked leaves carry per-chunk CRCs
+                sealed = [path for path in leaves if path not in chunked]
+                with TRACER.phase(SPAN_CKPT_SEAL, bytes=sum(leaves[path].nbytes for path in sealed)):
+                    for path in sealed:
+                        self.file.seal_checksum(f"{group}/state/{path}")
                 gen = self.file.commit()  # shadow flip: snapshot becomes durable
             save.tag("bytes", total_bytes)
             wall_s = time.perf_counter() - t0

@@ -9,10 +9,9 @@ else's:
      followed by an MPI prefix reduction to determine the amount added by all
      previous ranks to the global sum."
 
-This module is the *host-side* planner (pure numpy, used by the checkpoint
-writer and the benchmarks).  ``core.collective_io`` re-implements the same
-plan *on-device* with ``jax.lax`` collectives under ``shard_map`` and is
-tested to agree bit-for-bit.
+This module is the one planner (pure numpy, used by the checkpoint writer
+and the benchmarks): the plan is a few integers per rank, computed on the
+host where the writes are issued.
 
 The plans feed the zero-copy write pipeline: each planned extent becomes a
 view-carrying ``WriteRequest`` (``aggregation.nd_slab_requests`` — no

@@ -472,7 +472,9 @@ class ServiceServer:
                 self._n_accepted += 1
                 conn = _Conn(self, sock, f"th5-wire-{self._n_accepted}")
                 self._conns.add(conn)  # registered BEFORE its threads run
-            conn.start()
+                # started under the lock too: close() joins every registered
+                # connection, and a thread not yet started cannot be joined
+                conn.start()
 
     def _setup_conn(self, sock: socket.socket) -> None:
         if sock.family == socket.AF_INET:

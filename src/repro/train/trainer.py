@@ -40,7 +40,6 @@ class TrainerConfig:
     total_steps: int = 100
     checkpoint_every: int = 20
     log_every: int = 10
-    async_checkpoint: bool = True
     straggler_factor: float = 3.0
     keep_metrics: bool = True
 
@@ -116,10 +115,7 @@ class Trainer:
             "train_state": self.state,
             "data": self.stream.state(step),
         }
-        if self.tcfg.async_checkpoint:
-            self.async_ckpt.save(step, payload, overwrite=True)
-        else:
-            self.manager.save(step, payload, overwrite=True)
+        self.async_ckpt.save(step, payload, overwrite=True)
 
     # -- the loop -----------------------------------------------------------------
 

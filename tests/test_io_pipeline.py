@@ -459,17 +459,3 @@ def test_async_checkpointer_single_buffer_mode(tmp_path):
     ac.wait()
     np.testing.assert_array_equal(mgr.restore(1)[1]["b"], _state(1)["b"])
     mgr.close()
-
-
-def test_device_pack_linear_does_not_retrace():
-    jax = pytest.importorskip("jax")
-    import jax.numpy as jnp
-
-    from repro.core.collective_io import _pack_linear, device_pack_linear
-
-    bufs = [jnp.ones((4, 2), jnp.float32), jnp.arange(3, dtype=jnp.int32)]
-    a = device_pack_linear(bufs)
-    b = device_pack_linear([x + 0 for x in bufs])
-    assert a.shape == b.shape == (4 * 2 * 4 + 3 * 4,)
-    if hasattr(_pack_linear, "_cache_size"):
-        assert _pack_linear._cache_size() == 1  # same signature → one trace

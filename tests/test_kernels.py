@@ -9,7 +9,6 @@ import pytest
 from repro.kernels.attention.flash import flash_attention
 from repro.kernels.attention.ops import mha
 from repro.kernels.attention.ref import attention_ref
-from repro.kernels.pack.linear import pack_grids, pack_grids_ref
 from repro.kernels.ssd.chunk import ssd_chunk
 from repro.kernels.ssd.ops import ssd_scan
 from repro.kernels.ssd.ref import ssd_chunk_ref
@@ -138,17 +137,3 @@ def test_jacobi_converges_on_poisson():
         p = p.at[:, 1:-1, 1:-1].set(interior)
     r1 = float(jnp.abs(residual(p, f, h2=1.0, interpret=True)).mean())
     assert r1 < r0 * 0.2
-
-
-# -- pack ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("G,n", [(4, 16), (2, 8), (1, 32), (12, 16)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
-def test_pack_grids_matches_ref(G, n, dtype):
-    if dtype == jnp.int32:
-        p = jax.random.randint(KEY, (G, n + 2, n + 2), 0, 1000, dtype)
-    else:
-        p = jax.random.normal(KEY, (G, n + 2, n + 2), dtype)
-    got = pack_grids(p, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(pack_grids_ref(p)))

@@ -13,11 +13,12 @@ import numpy as np
 import pytest
 
 from repro.cfd.scenarios import karman_vortex
-from repro.cfd.sim import FIELDS, Simulation
+from repro.cfd.sim import FIELDS, Simulation, stage_rows
 from repro.cfd.spacetree import to_blocked
 from repro.core.checkpoint import CheckpointManager
 
 CHANNELS = [(32, 128, 16), (40, 160, 8)]  # nx, ny, the d-grid side it gets
+LAYOUTS = [(2, 2, 16), (1, 2, 8), (1, 1, 32), (3, 4, 16)]  # gx, gy, n
 
 
 def host_cells(sim):
@@ -102,3 +103,35 @@ def test_snapshot_restore_snapshot_gives_back_the_device_fields(sim):
     assert sim.restore(b) == b
     for f, want in at_b.items():
         assert np.array_equal(np.asarray(sim.state[f]), want), f
+
+
+def numpy_rows(fields, gx, gy, n, dtype):
+    """Row ``g = i·gy + j`` is d-grid (i, j): its n x n interior cells in
+    row-major order, the fields interleaved cell by cell."""
+    x = np.stack(fields, axis=-1).astype(dtype)  # (gx·n, gy·n, F)
+    return x.reshape(gx, n, gy, n, len(fields)).transpose(0, 2, 1, 3, 4).reshape(gx * gy, n * n * len(fields))
+
+
+@pytest.mark.parametrize("gx, gy, n", LAYOUTS)
+@pytest.mark.parametrize("dtype, n_fields", [(np.float32, 4), (np.int8, 1)], ids=["cells", "cell_type"])
+def test_stage_rows_is_the_numpy_packing(gx, gy, n, dtype, n_fields):
+    rng = np.random.default_rng(gx * 100 + gy * 10 + n)
+    shape = (gx * n, gy * n)
+    if dtype == np.int8:  # cell types, as the state holds them
+        fields = [rng.integers(0, 5, shape).astype(np.int8)]
+    else:
+        fields = [rng.standard_normal(shape, np.float32) for _ in range(n_fields)]
+    got = np.asarray(stage_rows(tuple(jnp.asarray(f) for f in fields), gx=gx, gy=gy, n=n, dtype=dtype))
+    want = numpy_rows(fields, gx, gy, n, dtype)
+    assert got.shape == want.shape == (gx * gy, n * n * n_fields)
+    assert got.dtype == want.dtype
+    assert got.flags.c_contiguous
+    assert got.tobytes() == want.tobytes()
+
+
+def test_snapshots_of_one_layout_do_not_retrace_the_stage(sim):
+    sim.snapshot()
+    traced = stage_rows._cache_size()
+    advance(sim, 1, sim.rng)
+    sim.snapshot()
+    assert stage_rows._cache_size() == traced

@@ -16,7 +16,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.attention.flash import flash_attention
-from repro.kernels.pack.linear import pack_grids
 from repro.kernels.stencil.jacobi import jacobi_sweep, residual
 
 # d-grids of 16² with a halo of 1: the snapshot cell's 65,536 grids
@@ -63,28 +62,34 @@ def test_residual_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("shape", [GRIDS_16, (16384, 34, 34)])
-def test_pack_grids_compiles_for_v5e(one_chip, shape):
-    p = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-    assert "tpu_custom_call" in _compiled_hlo(pack_grids, p)
-
-
 def test_flash_attention_compiles_for_v5e_at_qwen3_head_dim(one_chip):
     # qwen3-8b: 32 heads of head_dim 128, one sequence of 4096 tokens
     q = jax.ShapeDtypeStruct((32, 4096, 128), jnp.bfloat16, sharding=one_chip)
     assert "tpu_custom_call" in _compiled_hlo(flash_attention, q, q, q)
 
 
-@pytest.mark.parametrize("dtype, n_fields", [(jnp.float32, 4), (jnp.int8, 1)], ids=["cells", "cell_type"])
-def test_snapshot_rows_leave_the_v5e_row_major(one_chip, dtype, n_fields):
-    """The snapshot cell's rows (3072 x 12288 cells, d-grids of 16²) come
-    out of the device pack with the row as the minor dimension, so their
-    copy to the host is C-ordered and needs no reordering there."""
+@pytest.mark.parametrize(
+    "cells, n, dtype, n_fields",
+    [
+        ((3072, 12288), 16, jnp.float32, 4),
+        ((3072, 12288), 16, jnp.int8, 1),
+        ((2048, 8192), 16, jnp.float32, 4),
+        ((2048, 8192), 32, jnp.float32, 4),
+    ],
+    ids=["cells", "cell_type", "cells_2048x8192_n16", "cells_2048x8192_n32"],
+)
+def test_snapshot_rows_leave_the_v5e_row_major(one_chip, cells, n, dtype, n_fields):
+    """The snapshot cell's rows (3072 x 12288 cells, d-grids of 16²) and
+    the smoke channel's (2048 x 8192 cells, 65,536 d-grids of 16² or
+    16,384 of 32²) come out of the device pack with the row as the minor
+    dimension, so their copy to the host is C-ordered and needs no
+    reordering there."""
     from repro.cfd.sim import stage_rows
 
-    field = jax.ShapeDtypeStruct((3072, 12288), dtype, sharding=one_chip)
-    compiled = stage_rows.lower((field,) * n_fields, gx=192, gy=768, n=16, dtype=dtype).compile()
-    assert compiled.out_info.shape == (192 * 768, 256 * n_fields)
+    gx, gy = cells[0] // n, cells[1] // n
+    field = jax.ShapeDtypeStruct(cells, dtype, sharding=one_chip)
+    compiled = stage_rows.lower((field,) * n_fields, gx=gx, gy=gy, n=n, dtype=dtype).compile()
+    assert compiled.out_info.shape == (gx * gy, n * n * n_fields)
     assert compiled.output_formats.layout.major_to_minor == (0, 1)
 
 

@@ -2,6 +2,7 @@
 gradient compression, straggler watchdog."""
 
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -166,6 +167,30 @@ def test_straggler_watchdog(tmp_path):
     assert t.straggler.flagged == 1
     assert t.straggler.slowest_s == pytest.approx(0.1)
     t.manager.close()
+
+
+def test_checkpoint_returns_with_the_write_in_flight(tmp_path):
+    """``_checkpoint`` stages the state and returns while its write runs on
+    the saver's thread; the step is in the file once the saver is waited on."""
+    release = threading.Event()
+
+    class Held(CheckpointManager):
+        def save(self, step, state, **kw):
+            assert release.wait(60)
+            return super().save(step, state, **kw)
+
+    mgr = Held(str(tmp_path / "h.th5"))
+    t = Trainer(tiny_cfg(), mgr, data=DataConfig(batch=4, seq_len=64, seed=7))
+    t.init_or_resume()
+    t._checkpoint(0)
+    try:
+        assert t.async_ckpt._thread.is_alive()
+        assert mgr.latest_valid() is None
+    finally:
+        release.set()
+    t.async_ckpt.wait()
+    assert mgr.latest_valid() == 0
+    mgr.close()
 
 
 PLACEMENT_CODE = r"""
